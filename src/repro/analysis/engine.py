@@ -21,15 +21,15 @@ Pieces:
   on any STALE baseline entry: the baseline can only drift by being
   regenerated (``--write-baseline``) in a reviewed commit.
 * config — ``[tool.repro-analysis]`` in ``pyproject.toml`` (paths to scan,
-  excluded seed-era dirs, baseline location, fault-registry module). Parsed
-  with a deliberately tiny reader: this interpreter predates ``tomllib``
-  and the section only holds strings and string lists.
+  excluded seed-era dirs, baseline location, fault-registry module), read
+  with ``tomllib``.
 """
 from __future__ import annotations
 
 import ast
 import json
 import re
+import tomllib
 from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Iterable, Iterator, Sequence
@@ -128,46 +128,6 @@ class AnalysisConfig:
                    for ex in self.exclude)
 
 
-def _parse_toml_section(text: str, section: str) -> dict:
-    """Minimal TOML reader for one ``[section]`` of flat ``key = value``
-    pairs where value is a string or a (possibly multi-line) string list.
-    Good enough for our own config block; not a general TOML parser."""
-    out: dict = {}
-    lines = text.splitlines()
-    in_section = False
-    pending_key: str | None = None
-    pending_items: list[str] = []
-    for raw in lines:
-        line = raw.strip()
-        if line.startswith("["):
-            in_section = line == f"[{section}]"
-            pending_key = None
-            continue
-        if not in_section or not line or line.startswith("#"):
-            continue
-        if pending_key is not None:
-            pending_items.extend(re.findall(r'"([^"]*)"', line))
-            if line.rstrip(",").endswith("]"):
-                out[pending_key] = pending_items
-                pending_key, pending_items = None, []
-            continue
-        m = re.match(r'([A-Za-z0-9_-]+)\s*=\s*(.*)$', line)
-        if not m:
-            continue
-        key, val = m.group(1), m.group(2).strip()
-        if val.startswith("["):
-            items = re.findall(r'"([^"]*)"', val)
-            if val.rstrip(",").endswith("]"):
-                out[key] = items
-            else:
-                pending_key, pending_items = key, items
-        else:
-            sm = re.match(r'"([^"]*)"', val)
-            if sm:
-                out[key] = sm.group(1)
-    return out
-
-
 def load_config(root: str | Path | None = None) -> AnalysisConfig:
     """Read ``[tool.repro-analysis]`` from ``<root>/pyproject.toml``. With
     no ``root``, walk up from this file to the directory holding one (the
@@ -184,7 +144,8 @@ def load_config(root: str | Path | None = None) -> AnalysisConfig:
     cfg = AnalysisConfig(root=root)
     pyproject = root / "pyproject.toml"
     if pyproject.is_file():
-        data = _parse_toml_section(pyproject.read_text(), "tool.repro-analysis")
+        data = (tomllib.loads(pyproject.read_text())
+                .get("tool", {}).get("repro-analysis", {}))
         for key in ("paths", "exclude"):
             if key in data:
                 setattr(cfg, key, list(data[key]))

@@ -44,12 +44,15 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
                                 preferred_element_type=jnp.float32) * scale
         k_idx = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
         s = jnp.where(k_idx <= pos, s, NEG_INF)   # (1, BK)
-        m_prev = m_scr[0, 0]
-        m_cur = jnp.maximum(m_prev, s.max())
+        # statistics are (1, 1) vectors, stored lane-replicated as whole
+        # (1, 128) rows: Mosaic cannot store a scalar to VMEM
+        m_prev = m_scr[0:1, 0:1]
+        m_cur = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         corr = jnp.exp(m_prev - m_cur)
         p = jnp.exp(s - m_cur)
-        l_scr[0, 0] = l_scr[0, 0] * corr + p.sum()
-        m_scr[0, 0] = m_cur
+        l_new = l_scr[0:1, 0:1] * corr + p.sum(axis=1, keepdims=True)
+        l_scr[0:1, :] = jnp.broadcast_to(l_new, (1, l_scr.shape[1]))
+        m_scr[0:1, :] = jnp.broadcast_to(m_cur, (1, m_scr.shape[1]))
         pv = jax.lax.dot_general(p.astype(v.dtype), v,
                                  (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -57,7 +60,7 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        denom = jnp.maximum(l_scr[0, 0], 1e-30)
+        denom = jnp.maximum(l_scr[0:1, 0:1], 1e-30)
         o_ref[0, 0] = (acc_scr[0:1, :] / denom).astype(o_ref.dtype)
 
 

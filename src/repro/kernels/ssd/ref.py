@@ -64,11 +64,13 @@ def ssd_chunked(x, dt, A, B, C, chunk: int = 64, initial_state=None):
 
     # ---- intra-chunk (quadratic within chunk) -----------------------------
     scores = jnp.einsum("bcihn,bcjhn->bchij", Cf, Bf)
-    li = cum[:, :, :, :, ]                         # (b,c,i,h)
-    L = jnp.exp(li.transpose(0, 1, 3, 2)[..., :, None]
-                - cum.transpose(0, 1, 3, 2)[..., None, :])   # (b,c,h,i,j)
+    ct = cum.transpose(0, 1, 3, 2)                 # (b,c,h,i)
     iq = jnp.arange(q)
-    L = jnp.where(iq[:, None] >= iq[None, :], L, 0.0)
+    # mask the exponent, not its exp: above the diagonal cum_i - cum_j > 0
+    # overflows to inf, and where()'s gradient would then be inf * 0 = NaN
+    seg = jnp.where(iq[:, None] >= iq[None, :],
+                    ct[..., :, None] - ct[..., None, :], -jnp.inf)
+    L = jnp.exp(seg)                               # (b,c,h,i,j)
     M = scores * L * dtf.transpose(0, 1, 3, 2)[..., None, :]  # dt_j
     y_intra = jnp.einsum("bchij,bcjhp->bcihp", M, xf)
 

@@ -27,6 +27,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import configs
 from ..configs.shapes import SHAPES, applicable, input_specs
+from ..launch.compile_cache import use_compile_cache
 from ..launch.hlo_analysis import collective_bytes, cost_stats, memory_stats
 from ..launch.jaxpr_cost import loop_trip_table, traced_cost
 from ..launch.mesh import make_production_mesh
@@ -209,11 +210,9 @@ def main() -> int:
                     help="artifact directory suffix (e.g. -fsdp)")
     ap.add_argument("--list", action="store_true")
     ap.add_argument("--out", default=str(ARTIFACTS))
-    ap.add_argument("--cache-dir", default="/tmp/jax_cache")
     args = ap.parse_args()
 
-    jax.config.update("jax_compilation_cache_dir", args.cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    use_compile_cache()
 
     cells = []
     for arch in (configs.ARCHS if args.arch is None else [args.arch]):

@@ -55,7 +55,7 @@ class Server:
         self._decode = make_decode_fn(model)
         self.served = 0
 
-    def _batch_prompts(self, ffs) -> tuple[np.ndarray, list[str]]:
+    def batch_prompts(self, ffs) -> tuple[np.ndarray, list[str]]:
         s = self.scfg
         toks = np.full((len(ffs), s.prompt_len), self.tok.PAD, np.int32)
         ids = []
@@ -75,7 +75,7 @@ class Server:
             return 0
         while len(recs) < s.batch_size:   # pad batch with a copy (masked out)
             recs.append(recs[0])
-        toks, req_ids = self._batch_prompts(recs)
+        toks, req_ids = self.batch_prompts(recs)
         batch = {"tokens": jnp.asarray(toks)}
         logits, cache = self._prefill(self.params, batch)
         out_tokens = np.zeros((toks.shape[0], s.max_new_tokens), np.int32)

@@ -172,8 +172,23 @@ class Trainer:
     # -- lifecycle ------------------------------------------------------------
     def init_state(self) -> None:
         rng = jax.random.PRNGKey(self.tcfg.seed)
-        self.params = self.model.init(rng)
-        self.opt_state = adamw_init(self.params)
+        if self.mesh is None:
+            self.params = self.model.init(rng)
+            self.opt_state = adamw_init(self.params)
+            return
+        # on a mesh every leaf is created already sharded: no device ever
+        # holds the whole parameter or optimizer state
+        def shardings(specs):
+            return jax.tree.map(lambda sp: NamedSharding(self.mesh, sp), specs,
+                                is_leaf=lambda x: isinstance(x, P))
+        pspecs = param_spec_tree(self.model.cfg, self.mesh,
+                                 self.model.parallelism)
+        self.params = jax.jit(self.model.init,
+                              out_shardings=shardings(pspecs))(rng)
+        self.opt_state = jax.jit(
+            adamw_init,
+            out_shardings=shardings(opt_spec_tree(self.model, self.mesh)))(
+                self.params)
 
     def resume(self) -> bool:
         """Restore newest intact checkpoint; returns True if resumed."""
@@ -220,6 +235,7 @@ class Trainer:
             if self.step_idx % self.tcfg.log_every == 0 or trained == steps:
                 row = {k: float(v) for k, v in metrics.items()}
                 row["step"] = self.step_idx
+                row["elapsed_sec"] = time.monotonic() - t0
                 row["starved_polls"] = self.loader.starved_polls
                 self.history.append(row)
             if self.tcfg.ckpt_every and self.step_idx % self.tcfg.ckpt_every == 0:

@@ -1,8 +1,5 @@
 """Tokenizer, packing, streaming loader: determinism + exactly-once."""
 import numpy as np
-import pytest
-
-pytest.importorskip("hypothesis", reason="hypothesis not installed")
 from hypothesis import given, settings, strategies as st
 
 from repro.core import ConsumerGroup, PartitionedLog, make_flowfile

@@ -6,7 +6,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-pytest.importorskip("hypothesis", reason="hypothesis not installed")
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels.decode_attention.kernel import decode_attention
@@ -163,6 +162,22 @@ def test_ssd_chunked_xla_matches_sequential_long():
                                rtol=3e-4, atol=3e-4)
     np.testing.assert_allclose(np.asarray(s1), np.asarray(s2),
                                rtol=3e-4, atol=3e-4)
+
+
+def test_ssd_chunked_gradient_finite_under_strong_decay():
+    """At the init's strongest decay (A = -16) and dt = 0.1, a 256-step
+    chunk's segment sums reach exp(+409) above the diagonal: the masked
+    entries must not turn the backward pass into inf * 0 = NaN."""
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    b, s, h, p, n = 1, 512, 2, 8, 8
+    x = jax.random.normal(ks[0], (b, s, h, p))
+    B = jax.random.normal(ks[1], (b, s, h, n))
+    C = jax.random.normal(ks[2], (b, s, h, n))
+    A = jnp.full((h,), -16.0)
+    grads = jax.grad(
+        lambda dt, A: ssd_ref.ssd_chunked(x, dt, A, B, C, chunk=256)[0].sum(),
+        argnums=(0, 1))(jnp.full((b, s, h), 0.1), A)
+    assert all(bool(jnp.isfinite(g).all()) for g in grads)
 
 
 @given(chunk=st.sampled_from([16, 32, 64]), s_mult=st.integers(2, 6))

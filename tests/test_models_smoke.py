@@ -56,19 +56,7 @@ def test_forward_shapes_and_dtype(arch, rng):
     assert np.isfinite(np.asarray(logits, np.float32)).all()
 
 
-# known numeric mismatch between olmoe's MoE decode cache path and the full
-# forward, present since the seed commit on this container's jax build; a
-# non-strict xfail keeps the suite green without masking regressions in the
-# other archs, and a future fix surfaces as XPASS
-_PREFILL_DECODE_ARCHS = [
-    pytest.param(a, marks=pytest.mark.xfail(
-        reason="olmoe prefill/decode numeric mismatch "
-               "(pre-existing at seed)", strict=False))
-    if a == "olmoe-1b-7b" else a
-    for a in ARCHS]
-
-
-@pytest.mark.parametrize("arch", _PREFILL_DECODE_ARCHS)
+@pytest.mark.parametrize("arch", ARCHS)
 def test_prefill_decode_matches_forward(arch, rng):
     """Teacher-forced decode after prefill must reproduce the full-sequence
     forward logits (the KV/SSM cache path is numerically consistent)."""

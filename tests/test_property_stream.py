@@ -1,9 +1,6 @@
 """Hypothesis property tests on the ingestion fabric's invariants."""
 import json
 
-import pytest
-
-pytest.importorskip("hypothesis", reason="hypothesis not installed")
 from hypothesis import HealthCheck, given, settings, strategies as st
 
 from repro.core import (Connection, DetectDuplicate, OffsetStore,

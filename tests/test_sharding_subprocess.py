@@ -14,17 +14,6 @@ import sys
 import textwrap
 from pathlib import Path
 
-import pytest
-
-# capability gate: repro.launch.mesh builds meshes with jax.sharding.AxisType
-# (jax >= 0.6); on containers whose jax predates it these subprocess tests
-# cannot pass for reasons unrelated to this repo's code
-jax_sharding = pytest.importorskip("jax.sharding")
-pytestmark = pytest.mark.skipif(
-    not hasattr(jax_sharding, "AxisType"),
-    reason="container jax lacks jax.sharding.AxisType "
-           "(required by repro.launch.mesh)")
-
 ROOT = Path(__file__).resolve().parent.parent
 
 
@@ -129,3 +118,46 @@ def test_dryrun_machinery_on_tiny_mesh():
     """)
     r = run_sub(code)
     assert "DRYRUN_OK" in r.stdout, f"stdout={r.stdout}\nstderr={r.stderr[-3000:]}"
+
+
+def test_trainer_init_state_is_sharded_on_mesh(tmp_path):
+    """On a mesh, Trainer.init_state creates every parameter and optimizer
+    leaf with its spec's sharding, holding the values of the unsharded
+    init (the four-chip smoke compares against that init on one chip)."""
+    code = textwrap.dedent(f"""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax, numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro import configs
+    from repro.launch.mesh import make_mesh
+    from repro.models import Model, param_spec_tree
+    from repro.optim import OptConfig
+    from repro.runtime import Trainer, TrainerConfig, opt_spec_tree
+
+    cfg = configs.get_reduced("hymba-1.5b")
+    mesh = make_mesh((2, 2), ("data", "model"))
+    model = Model(cfg, mesh)
+    tr = Trainer(model, None, OptConfig(),
+                 TrainerConfig(ckpt_dir=r"{tmp_path}"), mesh=mesh)
+    tr.init_state()
+    is_spec = lambda x: isinstance(x, P)
+    for tree, specs in ((tr.params, param_spec_tree(cfg, mesh)),
+                        (tr.opt_state, opt_spec_tree(model, mesh))):
+        leaves = jax.tree.leaves(tree)
+        spec_leaves = jax.tree.leaves(specs, is_leaf=is_spec)
+        assert len(leaves) == len(spec_leaves)
+        for x, sp in zip(leaves, spec_leaves):
+            assert x.sharding.is_equivalent_to(NamedSharding(mesh, sp),
+                                               x.ndim), (x.sharding, sp)
+    sharded = [s for x in jax.tree.leaves(tr.params)
+               for s in [x.sharding.spec] if any(a is not None for a in s)]
+    assert sharded, "no parameter is sharded"
+    whole = Model(cfg).init(jax.random.PRNGKey(0))
+    for a, b in zip(jax.tree.leaves(tr.params), jax.tree.leaves(whole)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    print("INIT_SHARDED_OK", len(sharded))
+    """)
+    r = run_sub(code)
+    assert "INIT_SHARDED_OK" in r.stdout, \
+        f"stdout={r.stdout}\nstderr={r.stderr[-3000:]}"

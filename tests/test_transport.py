@@ -46,8 +46,7 @@ def test_records_codec_roundtrip_deterministic():
 
 
 def test_records_codec_roundtrip_hypothesis():
-    st = pytest.importorskip("hypothesis.strategies")
-    from hypothesis import given, settings
+    from hypothesis import given, settings, strategies as st
 
     @settings(max_examples=200, deadline=None)
     @given(st.lists(st.tuples(st.binary(max_size=64),

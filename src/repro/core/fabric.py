@@ -61,7 +61,7 @@ from typing import Callable, Sequence
 from .logstore import LogStore
 from .telemetry import (FlightRecorder, ScrapeServer, merge_histogram_states,
                         render_histogram_state_text, serve_scrape,
-                        summarize_histogram_state)
+                        summarize_histogram_state, tracer)
 from .transport import (FenceTable, LogServer, RemoteLogStore, recv_ctrl,
                         send_ctrl, TransportError)
 
@@ -441,7 +441,10 @@ class IngestionFabric:
 
     def render_metrics_text(self) -> str:
         """Prometheus-style text exposition of the merged fabric
-        telemetry plus a few coordinator gauges."""
+        telemetry plus a few coordinator gauges, followed by this process's
+        spans and counters (``telemetry.tracer()``: a training loader
+        attached here shows its ``loader/*`` spans and ``loader_*``
+        counters beside the fabric's)."""
         status = self.status()
         lines = [render_histogram_state_text(self.telemetry_state())]
         lw = status["low_watermark"]
@@ -453,6 +456,7 @@ class IngestionFabric:
             f"repro_fabric_group_errors {len(status['group_errors'])}")
         for k, v in sorted(status["transport"].items()):
             lines.append(f'repro_fabric_transport{{counter="{k}"}} {v}')
+        lines.append(tracer().registry.render_text())
         return "\n".join(ln for ln in lines if ln) + "\n"
 
     def serve_metrics(self, port: int = 0,

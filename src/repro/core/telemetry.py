@@ -41,22 +41,37 @@ Design constraints, in order:
 post-mortem view dumped to JSON when a fabric worker dies or an acceptance
 scenario fails, so a red run shows *where* depth/latency diverged instead
 of a bare boolean.
+
+``Tracer`` carries the same registry past the log to the device hop: timed
+spans at step or batch granularity (loader, trainer) kept in a bounded
+ring and folded into ``span_seconds{span=...}``, plus monotonic counters.
+The process-wide one is reached through ``span()`` / ``count()`` /
+``tracer()``. Its registry is what an operator scrapes: a fabric's
+``render_metrics_text()`` (and so its ``serve_metrics()`` endpoint) ends
+with it, and a process without a fabric, such as a trainer reading a
+remote log, serves it with ``serve_scrape(tracer().registry.render_text)``.
+Each span also opens an annotation from an installable factory: the
+runtime installs ``jax.profiler.TraceAnnotation``, so under a profiler
+session every span lands in the device trace on its clock, and this
+module stays free of JAX.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import threading
 import time
 from collections import deque
 from contextlib import contextmanager
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
-from typing import Callable, Iterable, Mapping, Optional
+from typing import Callable, Iterable, Mapping, NamedTuple, Optional
 
 __all__ = [
     "NBUCKETS", "LatencyHistogram", "MetricsRegistry", "FlightRecorder",
     "ScrapeServer", "serve_scrape", "metric_key", "split_metric_key",
     "merge_histogram_states", "summarize_histogram_state",
-    "render_histogram_state_text",
+    "render_histogram_state_text", "Counter", "SpanRecord", "Tracer",
+    "tracer", "span", "count",
 ]
 
 #: Fixed bucket count. Bucket 0 holds sub-microsecond samples; bucket i
@@ -284,6 +299,24 @@ def render_histogram_state_text(state: Mapping[str, Mapping],
     return "\n".join(lines) + ("\n" if lines else "")
 
 
+class Counter:
+    """Monotonic counter; ``add`` is safe from any thread."""
+
+    __slots__ = ("_value", "_lock")
+
+    def __init__(self) -> None:
+        self._value = 0
+        self._lock = threading.Lock()
+
+    def add(self, n: int = 1) -> None:
+        with self._lock:
+            self._value += n
+
+    @property
+    def value(self) -> int:
+        return self._value
+
+
 class MetricsRegistry:
     """Process-local metric surface: named+labelled latency histograms plus
     pluggable gauge *sources* (callables returning ``{instance: {field:
@@ -299,6 +332,7 @@ class MetricsRegistry:
         self._clock = clock
         self._lock = threading.Lock()
         self._hists: dict[str, LatencyHistogram] = {}
+        self._counters: dict[str, Counter] = {}
         self._sources: dict[str, Callable[[], Mapping]] = {}
 
     # -- histograms ----------------------------------------------------------
@@ -333,6 +367,22 @@ class MetricsRegistry:
                 out.merge(h)
         return out
 
+    # -- counters ------------------------------------------------------------
+    def counter(self, name: str, **labels: str) -> "Counter":
+        """Get-or-create the monotonic counter for ``(name, labels)``."""
+        key = metric_key(name, labels)
+        with self._lock:
+            c = self._counters.get(key)
+            if c is None:
+                c = self._counters[key] = Counter()
+            return c
+
+    def counters(self) -> dict:
+        """``{canonical key: value}`` of every counter."""
+        with self._lock:
+            counters = list(self._counters.items())
+        return {key: c.value for key, c in counters}
+
     # -- gauge sources -------------------------------------------------------
     def register_source(self, kind: str, fn: Callable[[], Mapping]) -> None:
         """Register a gauge source. ``fn()`` must return ``{instance:
@@ -352,7 +402,8 @@ class MetricsRegistry:
                 gauges[kind] = {str(k): dict(v) for k, v in fn().items()}
             except Exception:           # a dying component must not kill scrape
                 gauges[kind] = {}
-        return {"gauges": gauges, "histograms": self.summaries()}
+        return {"gauges": gauges, "counters": self.counters(),
+                "histograms": self.summaries()}
 
     def render_text(self, prefix: str = "repro_") -> str:
         """Prometheus-style text exposition of ``collect()``."""
@@ -367,6 +418,10 @@ class MetricsRegistry:
                         continue
                     lines.append(
                         f'{prefix}{kind}_{field}{{{kind}="{inst}"}} {v}')
+        for key in sorted(snap["counters"]):
+            name, labels = split_metric_key(key)
+            suffix = f"{{{labels}}}" if labels else ""
+            lines.append(f"{prefix}{name}_total{suffix} {snap['counters'][key]}")
         text = "\n".join(lines) + ("\n" if lines else "")
         return text + render_histogram_state_text(
             self.histograms_state(), prefix=prefix)
@@ -407,6 +462,164 @@ class FlightRecorder:
         with open(path, "w", encoding="utf-8") as f:
             f.write(data)
         return str(path)
+
+
+# -- spans and counters -------------------------------------------------------
+class SpanRecord(NamedTuple):
+    """One finished span. ``t0``/``t1`` are on the tracer's clock
+    (``time.monotonic`` by default); ``parent_id`` is the span that was open
+    on the same thread; ``trace_id`` is the caller's (a step index) or the
+    parent's."""
+    id: int
+    parent_id: Optional[int]
+    name: str
+    trace_id: Optional[int]
+    t0: float
+    t1: float
+
+
+class Tracer:
+    """Spans and counters over one ``MetricsRegistry``.
+
+    ``span(name, trace_id=None)`` times a block with one clock pair and
+    keeps a ``SpanRecord`` in a ring of ``capacity`` (``dropped`` counts
+    what it pushed out, ``dropped_until`` the latest end among those), and
+    records the duration in ``span_seconds{span=<name>}``. ``count(name,
+    n, **labels)`` adds to the registry's counter ``(name, labels)``. Meant
+    for step or batch granularity: a span costs a clock pair, a ring append
+    and a histogram record, never work per record.
+
+    ``set_annotation(factory)``: each span also enters ``factory(name)``
+    unless it returns None: a context manager such as
+    ``jax.profiler.TraceAnnotation``, so that a profiler session records
+    the span on the device trace's clock.
+    """
+
+    def __init__(self, registry: Optional[MetricsRegistry] = None,
+                 capacity: int = 65536,
+                 clock: Optional[Callable[[], float]] = None) -> None:
+        if capacity <= 0:
+            raise ValueError("capacity must be positive")
+        self.registry = registry if registry is not None else MetricsRegistry()
+        self._clock = clock or time.monotonic
+        self._ring: deque = deque(maxlen=capacity)
+        self._lock = threading.Lock()
+        self._ids = itertools.count(1)
+        self._local = _OpenSpans()
+        self._annotation: Optional[Callable[[str], object]] = None
+        self.dropped = 0
+        self.dropped_until = float("-inf")
+
+    def set_annotation(self, factory: Optional[Callable[[str], object]]) -> None:
+        """Install (or, with None, remove) the per-span annotation factory."""
+        self._annotation = factory
+
+    # -- recording -----------------------------------------------------------
+    def span(self, name: str, trace_id: Optional[int] = None) -> "_Span":
+        """``with tracer.span("train/step", trace_id=i):``"""
+        return _Span(self, name, trace_id)
+
+    def record(self, name: str, t0: float, t1: float,
+               trace_id: Optional[int] = None) -> None:
+        """Keep a span measured elsewhere (a back-dated compile), as a child
+        of the span open on this thread."""
+        stack = self._local.stack
+        sp = _Span(self, name, trace_id)
+        sp._parent = stack[-1]._id if stack else None
+        if trace_id is None and stack:
+            sp._trace_id = stack[-1]._trace_id
+        sp._id, sp._t0 = next(self._ids), t0
+        sp._finish(t1)
+
+    def count(self, name: str, n: int = 1, **labels: str) -> None:
+        """Add ``n`` to the registry's counter ``(name, labels)``."""
+        self.registry.counter(name, **labels).add(n)
+
+    # -- reading -------------------------------------------------------------
+    def value(self, name: str, **labels: str) -> int:
+        """The counter ``(name, labels)`` (0 before its first ``count``)."""
+        return self.registry.counters().get(metric_key(name, labels), 0)
+
+    def spans(self, name: Optional[str] = None) -> list[SpanRecord]:
+        """The ring's records, oldest first (all, or those named ``name``)."""
+        with self._lock:
+            recs = list(self._ring)
+        return [SpanRecord(*r) for r in recs if name is None or r[2] == name]
+
+    def now(self) -> float:
+        return self._clock()
+
+
+class _OpenSpans(threading.local):
+    """Per thread: the open spans, innermost last."""
+
+    def __init__(self) -> None:
+        self.stack: list = []
+
+
+class _Span:
+    __slots__ = ("_tracer", "_name", "_trace_id", "_id", "_parent",
+                 "_stack", "_ann", "_t0")
+
+    def __init__(self, tracer: Tracer, name: str,
+                 trace_id: Optional[int]) -> None:
+        self._tracer, self._name, self._trace_id = tracer, name, trace_id
+
+    def __enter__(self) -> "_Span":
+        tr = self._tracer
+        stack = self._stack = tr._local.stack
+        if stack:
+            top = stack[-1]
+            self._parent = top._id
+            if self._trace_id is None:
+                self._trace_id = top._trace_id
+        else:
+            self._parent = None
+        self._id = next(tr._ids)
+        stack.append(self)
+        # the clock pair brackets the annotation: under a profiler session
+        # a span's time includes what its annotation costs, so children's
+        # times still add up to their parent's
+        self._t0 = tr._clock()
+        factory = tr._annotation
+        ann = self._ann = factory(self._name) if factory is not None else None
+        if ann is not None:
+            ann.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        t1 = self._tracer._clock()
+        self._stack.pop()
+        self._finish(t1)
+        return False
+
+    def _finish(self, t1: float) -> None:
+        """Ring the record and record its duration in its histogram."""
+        tr, name, t0 = self._tracer, self._name, self._t0
+        with tr._lock:
+            ring = tr._ring
+            if len(ring) == ring.maxlen:
+                tr.dropped += 1
+                if ring[0][5] > tr.dropped_until:
+                    tr.dropped_until = ring[0][5]
+            ring.append((self._id, self._parent, name, self._trace_id, t0, t1))
+        tr.registry.histogram("span_seconds", span=name).record(t1 - t0)
+
+
+_TRACER = Tracer()
+
+
+def tracer() -> Tracer:
+    """The process-wide tracer that the loader and the runtime record to."""
+    return _TRACER
+
+
+#: ``span(name, trace_id=None)`` and ``count(name, n=1, **labels)`` on the
+#: process-wide tracer (``Tracer.span``, ``Tracer.count``)
+span = _TRACER.span
+count = _TRACER.count
 
 
 # -- scrape endpoint ----------------------------------------------------------

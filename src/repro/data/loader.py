@@ -22,6 +22,7 @@ the loader produces the full global batch and the runtime shards it by
 """
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from collections import deque
@@ -32,8 +33,11 @@ import numpy as np
 from ..core.connection import Connection
 from ..core.delivery import Consumer
 from ..core.flowfile import FlowFile
+from ..core.telemetry import count, span, tracer
 from .packing import SequencePacker
 from .tokenizer import ByteTokenizer
+
+_LOADER_IDS = itertools.count()
 
 
 class StreamingDataLoader:
@@ -54,6 +58,8 @@ class StreamingDataLoader:
         self._rows: list[np.ndarray] = []
         self._batches_emitted = 0
         self.poll_records = poll_records
+        #: label of this loader's ``loader_*`` counters on the process tracer
+        self.loader_id = str(next(_LOADER_IDS))
         # host→device prefetch queue with backpressure. The assembler ships
         # *chunks* of up to ``prefetch_chunk`` batches per queue envelope:
         # the CPU-bound assembler thread only yields the GIL every switch
@@ -74,7 +80,6 @@ class StreamingDataLoader:
         self._stop = threading.Event()
         self._threads: list[threading.Thread] = []
         self._state_lock = threading.Lock()
-        self._starved_polls = 0
 
     # ------------------------------------------------------------------
     # Synchronous path (used by tests, dry runs, and the exactly-once
@@ -91,32 +96,56 @@ class StreamingDataLoader:
         texts = [self.text_fn(FlowFile.from_record(rec.key, rec.value))
                  for rec in records]
         encode_batch = getattr(self.tokenizer, "encode_batch", None)
+        n_rows = len(self._rows)
         if encode_batch is None:
+            n_tokens = 0
             for text in texts:
-                self._rows.extend(
-                    self.packer.add_document(self.tokenizer.encode(text)))
-            return
-        rows = self.packer.add_tokens(encode_batch(texts))
-        if len(rows):
-            self._rows.extend(rows)
+                ids = self.tokenizer.encode(text)
+                n_tokens += len(ids)
+                self._rows.extend(self.packer.add_document(ids))
+        else:
+            ids = encode_batch(texts)
+            n_tokens = len(ids)
+            rows = self.packer.add_tokens(ids)
+            if len(rows):
+                self._rows.extend(rows)
+        count("loader_tokens", n_tokens, loader=self.loader_id)
+        count("loader_rows", len(self._rows) - n_rows, loader=self.loader_id)
 
     def next_batch(self, timeout_polls: int = 10_000) -> np.ndarray | None:
         """Assemble one (batch_size, seq_len+1) batch synchronously.
-        Returns None when the stream is exhausted before a full batch."""
-        with self._state_lock:
+        Returns None when the stream is exhausted before a full batch.
+
+        Spans: ``loader/next_batch``, holding a ``loader/poll`` per
+        ``consumer.poll`` and ``loader/pack`` around tokenizing, packing
+        and the final stack; counters ``loader_records``,
+        ``loader_bytes``, ``loader_starved_polls``, ``loader_tokens``,
+        ``loader_rows``, ``loader_batches``, each labelled
+        ``loader=<loader_id>``."""
+        with span("loader/next_batch"), self._state_lock:
             polls = 0
             while len(self._rows) < self.batch_size:
-                recs = self.consumer.poll(self.poll_records)
+                with span("loader/poll"):
+                    recs = self.consumer.poll(self.poll_records)
+                    if recs:
+                        count("loader_records", len(recs),
+                              loader=self.loader_id)
+                        count("loader_bytes", sum(len(r.value) for r in recs),
+                              loader=self.loader_id)
+                    else:
+                        count("loader_starved_polls", loader=self.loader_id)
                 if not recs:
                     polls += 1
-                    self._starved_polls += 1
                     if polls >= timeout_polls:
                         return None
                     continue
-                self._ingest_records(recs)
-            batch = np.stack(self._rows[:self.batch_size])
-            del self._rows[:self.batch_size]
-            self._batches_emitted += 1
+                with span("loader/pack"):
+                    self._ingest_records(recs)
+            with span("loader/pack"):
+                batch = np.stack(self._rows[:self.batch_size])
+                del self._rows[:self.batch_size]
+                self._batches_emitted += 1
+                count("loader_batches", loader=self.loader_id)
             return batch
 
     # ------------------------------------------------------------------
@@ -195,9 +224,10 @@ class StreamingDataLoader:
 
     @property
     def starved_polls(self) -> int:
-        """Times the loader polled an empty stream — the 'ingestion is the
+        """Times this loader polled an empty stream (its
+        ``loader_starved_polls`` counter) — the 'ingestion is the
         bottleneck' signal surfaced to the trainer's metrics."""
-        return self._starved_polls
+        return tracer().value("loader_starved_polls", loader=self.loader_id)
 
 
 class _BatchEnvelope:

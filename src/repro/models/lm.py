@@ -87,6 +87,7 @@ class Model:
         return abstract_params(self.cfg, self.mesh, self.parallelism)
 
     # -- embedding / head -------------------------------------------------------
+    @jax.named_scope("embed")
     def _embed(self, params, tokens):
         h = jnp.take(params["embed"], tokens, axis=0)
         return self.ctx.act(h)
@@ -98,6 +99,7 @@ class Model:
         n = img.shape[1]
         return jnp.concatenate([img, h[:, n:]], axis=1)
 
+    @jax.named_scope("head")
     def _logits(self, params, h):
         h = rms_norm(h, params["final_norm"], self.cfg.norm_eps)
         return h @ params["lm_head"]
@@ -119,6 +121,7 @@ class Model:
             k = apply_rope(k, positions, cfg.rope_theta)
         return q, k, v
 
+    @jax.named_scope("attention")
     def _attn_full_seq(self, x, ap, positions, mode: str, *, window: int = 0,
                        bidir: bool = False, want_cache: bool = False):
         """Self-attention over a full sequence (train or prefill)."""
@@ -176,6 +179,7 @@ class Model:
             seq = "model" if not self.ctx.kv_head_sharded else None
         return (bshard, seq)
 
+    @jax.named_scope("attention")
     def _attn_decode(self, x, ap, cache_l, pos, *, window: int = 0):
         """One-token self-attention against a cache (ring buffer when SWA)."""
         cfg, ctx = self.cfg, self.ctx
@@ -230,6 +234,10 @@ class Model:
     # ------------------------------------------------------------------
     # Per-family blocks. Each returns (h, extras).
     # ------------------------------------------------------------------
+    @jax.named_scope("mlp")
+    def _ffn(self, x, fp):
+        return ffn_forward(x, fp, self.cfg.ffn, self.ctx)
+
     def _block_dense(self, h, lp, positions, mode, want_cache=False,
                      window=0, bidir=False):
         cfg, ctx = self.cfg, self.ctx
@@ -239,7 +247,7 @@ class Model:
                                           want_cache=want_cache)
         h = ctx.act(h + attn)
         x = rms_norm(h, lp["ffn_norm"], cfg.norm_eps)
-        h = ctx.act(h + ffn_forward(x, lp["ffn"], cfg.ffn, ctx))
+        h = ctx.act(h + self._ffn(x, lp["ffn"]))
         return h, cache
 
     def _block_dense_decode(self, h, lp, cache_l, pos, window=0):
@@ -249,14 +257,16 @@ class Model:
                                             window=window)
         h = ctx.act(h + attn)
         x = rms_norm(h, lp["ffn_norm"], cfg.norm_eps)
-        h = ctx.act(h + ffn_forward(x, lp["ffn"], cfg.ffn, ctx))
+        h = ctx.act(h + self._ffn(x, lp["ffn"]))
         return h, new_cache
 
     def _block_moe(self, h, lp, positions, mode, want_cache=False):
         cfg, ctx = self.cfg, self.ctx
         x = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
         if cfg.kv_lora:
-            attn, cache = mla.mla_full(x, lp["attn"], cfg, ctx, positions, mode)
+            with jax.named_scope("attention"):
+                attn, cache = mla.mla_full(x, lp["attn"], cfg, ctx, positions,
+                                           mode)
             if not want_cache:
                 cache = None
         else:
@@ -264,7 +274,8 @@ class Model:
                                               want_cache=want_cache)
         h = ctx.act(h + attn)
         x = rms_norm(h, lp["ffn_norm"], cfg.norm_eps)
-        y, aux = moe_forward(x, lp["moe"], cfg, ctx)
+        with jax.named_scope("mlp"):
+            y, aux = moe_forward(x, lp["moe"], cfg, ctx)
         h = ctx.act(h + y)
         return h, (cache, aux)
 
@@ -272,13 +283,15 @@ class Model:
         cfg, ctx = self.cfg, self.ctx
         x = rms_norm(h, lp["attn_norm"], cfg.norm_eps)
         if cfg.kv_lora:
-            attn, new_cache = mla.mla_decode(x, lp["attn"], cfg, ctx,
-                                             cache_l, pos)
+            with jax.named_scope("attention"):
+                attn, new_cache = mla.mla_decode(x, lp["attn"], cfg, ctx,
+                                                 cache_l, pos)
         else:
             attn, new_cache = self._attn_decode(x, lp["attn"], cache_l, pos)
         h = ctx.act(h + attn)
         x = rms_norm(h, lp["ffn_norm"], cfg.norm_eps)
-        y, _ = moe_forward(x, lp["moe"], cfg, ctx)
+        with jax.named_scope("mlp"):
+            y, _ = moe_forward(x, lp["moe"], cfg, ctx)
         h = ctx.act(h + y)
         return h, new_cache
 
@@ -314,7 +327,7 @@ class Model:
                        * f["beta_ssm"])
         h = ctx.act(h + fused)
         x = rms_norm(h, lp["ffn_norm"], cfg.norm_eps)
-        h = ctx.act(h + ffn_forward(x, lp["ffn"], cfg.ffn, ctx))
+        h = ctx.act(h + self._ffn(x, lp["ffn"]))
         cache = None
         if want_cache:
             if window:      # keep only the trailing ring window
@@ -338,7 +351,7 @@ class Model:
                        * f["beta_ssm"])
         h = ctx.act(h + fused)
         x = rms_norm(h, lp["ffn_norm"], cfg.norm_eps)
-        h = ctx.act(h + ffn_forward(x, lp["ffn"], cfg.ffn, ctx))
+        h = ctx.act(h + self._ffn(x, lp["ffn"]))
         return h, {"attn": new_ac, "ssm": new_sc}
 
     def _block_encdec_dec(self, h, lp, enc_out, positions, mode,
@@ -350,16 +363,18 @@ class Model:
         h = ctx.act(h + attn)
         x = rms_norm(h, lp["cross_norm"], cfg.norm_eps)
         b, s, _ = x.shape
-        q = (x @ lp["cross"]["wq"]).reshape(b, s, cfg.n_heads, cfg.d_head)
-        ek = (enc_out @ lp["cross"]["wk"]).reshape(
-            b, -1, cfg.n_kv_heads, cfg.d_head)
-        ev = (enc_out @ lp["cross"]["wv"]).reshape(
-            b, -1, cfg.n_kv_heads, cfg.d_head)
-        mask = jnp.ones((s, ek.shape[1]), bool)
-        cross = attention_train(q, ek, ev, mask, ctx)
-        h = ctx.act(h + cross.reshape(b, s, -1) @ lp["cross"]["wo"])
+        with jax.named_scope("attention"):
+            q = (x @ lp["cross"]["wq"]).reshape(b, s, cfg.n_heads, cfg.d_head)
+            ek = (enc_out @ lp["cross"]["wk"]).reshape(
+                b, -1, cfg.n_kv_heads, cfg.d_head)
+            ev = (enc_out @ lp["cross"]["wv"]).reshape(
+                b, -1, cfg.n_kv_heads, cfg.d_head)
+            mask = jnp.ones((s, ek.shape[1]), bool)
+            cross = attention_train(q, ek, ev, mask, ctx)
+            cross = cross.reshape(b, s, -1) @ lp["cross"]["wo"]
+        h = ctx.act(h + cross)
         x = rms_norm(h, lp["ffn_norm"], cfg.norm_eps)
-        h = ctx.act(h + ffn_forward(x, lp["ffn"], cfg.ffn, ctx))
+        h = ctx.act(h + self._ffn(x, lp["ffn"]))
         if want_cache:
             cache = {"self": cache, "cross_k": ek, "cross_v": ev}
         return h, cache
@@ -371,14 +386,16 @@ class Model:
         h = ctx.act(h + attn)
         x = rms_norm(h, lp["cross_norm"], cfg.norm_eps)
         b = x.shape[0]
-        q = (x @ lp["cross"]["wq"]).reshape(b, 1, cfg.n_heads, cfg.d_head)
         bspec, seq_spec = self._dec_hints
-        o = attention_decode(q, cache_l["cross_k"], cache_l["cross_v"],
-                             cache_l["cross_k"].shape[1] - 1, ctx,
-                             bspec=bspec, seq_spec=seq_spec)
-        h = ctx.act(h + o.reshape(b, 1, -1) @ lp["cross"]["wo"])
+        with jax.named_scope("attention"):
+            q = (x @ lp["cross"]["wq"]).reshape(b, 1, cfg.n_heads, cfg.d_head)
+            o = attention_decode(q, cache_l["cross_k"], cache_l["cross_v"],
+                                 cache_l["cross_k"].shape[1] - 1, ctx,
+                                 bspec=bspec, seq_spec=seq_spec)
+            o = o.reshape(b, 1, -1) @ lp["cross"]["wo"]
+        h = ctx.act(h + o)
         x = rms_norm(h, lp["ffn_norm"], cfg.norm_eps)
-        h = ctx.act(h + ffn_forward(x, lp["ffn"], cfg.ffn, ctx))
+        h = ctx.act(h + self._ffn(x, lp["ffn"]))
         return h, {"self": new_self, "cross_k": cache_l["cross_k"],
                    "cross_v": cache_l["cross_v"]}
 
@@ -397,7 +414,8 @@ class Model:
             return body(carry, x)
 
         wrapped = jax.checkpoint(pinned)
-        return jax.lax.scan(wrapped, h, xs)
+        with jax.named_scope("layer_stack"):
+            return jax.lax.scan(wrapped, h, xs)
 
     # ------------------------------------------------------------------
     # Full-sequence forward (train / prefill)
@@ -489,7 +507,8 @@ class Model:
         if cfg.family == "vlm":
             pos = jnp.arange(labels.shape[1])[None]
             mask &= pos >= cfg.img_tokens
-        loss = cross_entropy_loss(logits, labels, mask)
+        with jax.named_scope("head"):
+            loss = cross_entropy_loss(logits, labels, mask)
         return loss + MOE_AUX_WEIGHT * extras["aux"], {
             "ce_loss": loss, "aux_loss": extras["aux"]}
 

@@ -58,63 +58,72 @@ def _to_heads(xs, bs, cs, cfg):
     return x, bm, cm
 
 
+def _conv_heads(xs, bs, cs, p, cfg, dtype, cache=None):
+    """Causal convs of the x, B and C streams (from ``cache``'s carries in
+    decode), their SiLU, and the split into heads. Returns the heads and
+    the new conv carries."""
+    with jax.named_scope("conv"):
+        carry = {}
+        outs = []
+        for key, t in (("conv_x", xs), ("conv_B", bs), ("conv_C", cs)):
+            t, carry[key] = _causal_conv(
+                t, p[key], None if cache is None else cache[key])
+            outs.append(jax.nn.silu(t.astype(jnp.float32)).astype(dtype))
+        return (*_to_heads(*outs, cfg), carry)
+
+
+def _ssd_skip(x, dt, bm, cm, p, cfg, state=None):
+    """The SSD over the sequence (or, from ``state``, one decode step) plus
+    the D skip. Returns (y, final state)."""
+    with jax.named_scope("ssd"):
+        A = -jnp.exp(p["A_log"].astype(jnp.float32))
+        if state is None:
+            y, state = ssd_ops.ssd(x, dt, A, bm, cm, chunk=cfg.ssm_chunk)
+        else:
+            y, state = ssd_ops.ssd_decode_step(
+                state, x[:, 0], dt[:, 0], A, bm[:, 0], cm[:, 0])
+            y = y[:, None]
+        return y + x * p["D"].astype(x.dtype)[None, None, :, None], state
+
+
+def _gate_out(y, z, p, cfg, h):
+    """Gated RMSNorm, then the output projection."""
+    with jax.named_scope("gate_norm"):
+        y = y.reshape(h.shape[0], h.shape[1], cfg.d_inner)
+        y = rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
+                     p["gate_norm"], cfg.norm_eps)
+    with jax.named_scope("mixer_proj"):
+        return y @ p["out_proj"]
+
+
+def _streams(h, p, cfg, ctx):
+    with jax.named_scope("mixer_proj"):
+        return _project_streams(h, p, cfg, ctx)
+
+
 def mamba_forward(h, p, cfg, ctx: ShardCtx):
     """Training/prefill path over a full sequence. h: (B,S,d)."""
-    z, xs, bs, cs, dt = _project_streams(h, p, cfg, ctx)
-    xs, _ = _causal_conv(xs, p["conv_x"])
-    bs, _ = _causal_conv(bs, p["conv_B"])
-    cs, _ = _causal_conv(cs, p["conv_C"])
-    xs, bs, cs = (jax.nn.silu(t.astype(jnp.float32)).astype(h.dtype)
-                  for t in (xs, bs, cs))
-    x, bm, cm = _to_heads(xs, bs, cs, cfg)
-    A = -jnp.exp(p["A_log"].astype(jnp.float32))
-    y, _ = ssd_ops.ssd(x, dt, A, bm, cm, chunk=cfg.ssm_chunk)
-    y = y + x * p["D"].astype(x.dtype)[None, None, :, None]
-    y = y.reshape(h.shape[0], h.shape[1], cfg.d_inner)
-    y = rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
-                 p["gate_norm"], cfg.norm_eps)
-    return y @ p["out_proj"]
+    z, xs, bs, cs, dt = _streams(h, p, cfg, ctx)
+    x, bm, cm, _ = _conv_heads(xs, bs, cs, p, cfg, h.dtype)
+    y, _ = _ssd_skip(x, dt, bm, cm, p, cfg)
+    return _gate_out(y, z, p, cfg, h)
 
 
 def mamba_prefill(h, p, cfg, ctx: ShardCtx):
     """Like forward but also returns the recurrent cache for decode."""
-    z, xs, bs, cs, dt = _project_streams(h, p, cfg, ctx)
-    xs, conv_x_state = _causal_conv(xs, p["conv_x"])
-    bs, conv_b_state = _causal_conv(bs, p["conv_B"])
-    cs, conv_c_state = _causal_conv(cs, p["conv_C"])
-    xs, bs, cs = (jax.nn.silu(t.astype(jnp.float32)).astype(h.dtype)
-                  for t in (xs, bs, cs))
-    x, bm, cm = _to_heads(xs, bs, cs, cfg)
-    A = -jnp.exp(p["A_log"].astype(jnp.float32))
-    y, state = ssd_ops.ssd(x, dt, A, bm, cm, chunk=cfg.ssm_chunk)
-    y = y + x * p["D"].astype(x.dtype)[None, None, :, None]
-    y = y.reshape(h.shape[0], h.shape[1], cfg.d_inner)
-    y = rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
-                 p["gate_norm"], cfg.norm_eps)
-    cache = {"ssm": state,                                 # (B,H,N,P) fp32
-             "conv_x": conv_x_state, "conv_B": conv_b_state,
-             "conv_C": conv_c_state}
-    return y @ p["out_proj"], cache
+    z, xs, bs, cs, dt = _streams(h, p, cfg, ctx)
+    x, bm, cm, carry = _conv_heads(xs, bs, cs, p, cfg, h.dtype)
+    y, state = _ssd_skip(x, dt, bm, cm, p, cfg)
+    cache = {"ssm": state, **carry}                # ssm: (B,H,N,P) fp32
+    return _gate_out(y, z, p, cfg, h), cache
 
 
 def mamba_decode(h, p, cfg, ctx: ShardCtx, cache):
     """One-token step. h: (B,1,d). cache: {'ssm','conv_x','conv_B','conv_C'}."""
-    z, xs, bs, cs, dt = _project_streams(h, p, cfg, ctx)
-    xs, cx = _causal_conv(xs, p["conv_x"], cache["conv_x"])
-    bs, cb = _causal_conv(bs, p["conv_B"], cache["conv_B"])
-    cs, cc = _causal_conv(cs, p["conv_C"], cache["conv_C"])
-    xs, bs, cs = (jax.nn.silu(t.astype(jnp.float32)).astype(h.dtype)
-                  for t in (xs, bs, cs))
-    x, bm, cm = _to_heads(xs, bs, cs, cfg)
-    A = -jnp.exp(p["A_log"].astype(jnp.float32))
-    y, state = ssd_ops.ssd_decode_step(
-        cache["ssm"], x[:, 0], dt[:, 0], A, bm[:, 0], cm[:, 0])
-    y = y[:, None] + x * p["D"].astype(x.dtype)[None, None, :, None]
-    y = y.reshape(h.shape[0], 1, cfg.d_inner)
-    y = rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
-                 p["gate_norm"], cfg.norm_eps)
-    new_cache = {"ssm": state, "conv_x": cx, "conv_B": cb, "conv_C": cc}
-    return y @ p["out_proj"], new_cache
+    z, xs, bs, cs, dt = _streams(h, p, cfg, ctx)
+    x, bm, cm, carry = _conv_heads(xs, bs, cs, p, cfg, h.dtype, cache)
+    y, state = _ssd_skip(x, dt, bm, cm, p, cfg, cache["ssm"])
+    return _gate_out(y, z, p, cfg, h), {"ssm": state, **carry}
 
 
 def mamba_cache_shape(cfg, batch: int) -> dict:

@@ -64,6 +64,7 @@ def _decay_mask(path: str) -> float:
     return 1.0
 
 
+@jax.named_scope("optimizer")
 def adamw_update(grads, state, params, step, cfg: OptConfig,
                  path_tree=None):
     """Returns (new_params (model dtype), new_state). grads may be any float

@@ -16,11 +16,13 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..checkpoint import CheckpointManager, to_device
+from ..core.telemetry import span
 from ..data.loader import StreamingDataLoader
 from ..models import Model, param_spec_tree
 from ..models.common import dp_axes, unflatten, param_template
 from ..optim import (OptConfig, adamw_init, adamw_update, opt_state_specs,
                      path_tree_of)
+from . import tracing
 
 
 # ---------------------------------------------------------------------------
@@ -165,9 +167,15 @@ class Trainer:
         self.step_idx = 0
         self.params = None
         self.opt_state = None
-        self._step_fn = make_train_step(
+        # the jitted step, kept apart from ``_step_fn``: callers may wrap or
+        # replace ``_step_fn``, while ``op_scopes("train_step")`` compiles
+        # this one
+        self._jitted_step = make_train_step(
             model, opt_cfg, num_microbatches=tcfg.num_microbatches)
+        self._step_fn = self._dispatch
+        self._registered = False
         self.history: list[dict] = []
+        tracing.install()
 
     # -- lifecycle ------------------------------------------------------------
     def init_state(self) -> None:
@@ -215,32 +223,55 @@ class Trainer:
                              "step": self.step_idx})
 
     # -- main loop --------------------------------------------------------------
+    def _dispatch(self, params, opt_state, batch, step_idx):
+        """Enqueue the jitted step (``train/dispatch``; returns before the
+        device finishes)."""
+        with span("train/dispatch"):
+            return self._jitted_step(params, opt_state, batch, step_idx)
+
     def run(self, steps: int | None = None) -> dict:
+        """Train ``steps`` steps. Each is a ``train/step`` span (trace id:
+        the step index, also an xprof step marker) holding the loader's
+        spans, ``train/put``, ``train/dispatch``, and on logged steps
+        ``train/log_sync``, the only wait on the device."""
         steps = steps if steps is not None else self.tcfg.steps
         if self.params is None and not self.resume():
             self.init_state()
         t0 = time.monotonic()
         trained = 0
         while trained < steps:
-            if self.step_idx == self.tcfg.fail_at_step:
-                raise SimulatedFailure(f"injected at step {self.step_idx}")
-            batch_np = self.loader.next_batch()
-            if batch_np is None:
-                break                                   # stream exhausted
-            batch = shard_batch({"tokens": batch_np}, self.mesh)
-            self.params, self.opt_state, metrics = self._step_fn(
-                self.params, self.opt_state, batch, self.step_idx)
-            self.step_idx += 1
-            trained += 1
-            if self.step_idx % self.tcfg.log_every == 0 or trained == steps:
-                row = {k: float(v) for k, v in metrics.items()}
-                row["step"] = self.step_idx
-                row["elapsed_sec"] = time.monotonic() - t0
-                row["starved_polls"] = self.loader.starved_polls
-                self.history.append(row)
-            if self.tcfg.ckpt_every and self.step_idx % self.tcfg.ckpt_every == 0:
-                self.save()
-        self.ckpt.wait()
+            i = self.step_idx
+            if i == self.tcfg.fail_at_step:
+                raise SimulatedFailure(f"injected at step {i}")
+            with jax.profiler.StepTraceAnnotation("train", step_num=i), \
+                    span("train/step", trace_id=i):
+                batch_np = self.loader.next_batch()
+                if batch_np is None:
+                    break                               # stream exhausted
+                with span("train/put"):
+                    batch = shard_batch({"tokens": batch_np}, self.mesh)
+                if not self._registered:        # before donation
+                    tracing.register("train_step", self._jitted_step,
+                                     (self.params, self.opt_state, batch, i))
+                    self._registered = True
+                self.params, self.opt_state, metrics = self._step_fn(
+                    self.params, self.opt_state, batch, i)
+                self.step_idx += 1
+                trained += 1
+                if self.step_idx % self.tcfg.log_every == 0 or trained == steps:
+                    with span("train/log_sync"):
+                        host = jax.device_get(metrics)
+                    row = {k: float(v) for k, v in host.items()}
+                    row["step"] = self.step_idx
+                    row["elapsed_sec"] = time.monotonic() - t0
+                    row["starved_polls"] = self.loader.starved_polls
+                    self.history.append(row)
+                if (self.tcfg.ckpt_every
+                        and self.step_idx % self.tcfg.ckpt_every == 0):
+                    with span("train/checkpoint"):
+                        self.save()
+        with span("train/checkpoint"):
+            self.ckpt.wait()
         dt = time.monotonic() - t0
         return {"steps": trained, "wall_sec": dt,
                 "final_loss": self.history[-1]["loss"] if self.history else None}

@@ -232,3 +232,172 @@ def test_scrape_server_serves_metrics_text():
     finally:
         srv.close()
         srv.close()                              # idempotent
+
+
+# -- Tracer: spans and counters -----------------------------------------------
+
+class _FakeClock:
+    def __init__(self, step=0.5):
+        self.t, self.step = 0.0, step
+
+    def __call__(self):
+        self.t += self.step
+        return self.t
+
+
+def test_tracer_parents_and_trace_ids():
+    from repro.core.telemetry import Tracer
+    tr = Tracer(clock=_FakeClock())
+    with tr.span("train/step", trace_id=7):
+        with tr.span("loader/next_batch"):
+            with tr.span("loader/poll"):
+                pass
+        tr.record("compile", 0.1, 0.2)
+    with tr.span("other"):
+        pass
+    recs = {r.name: r for r in tr.spans()}
+    step = recs["train/step"]
+    assert step.parent_id is None and step.trace_id == 7
+    assert recs["loader/next_batch"].parent_id == step.id
+    assert recs["loader/poll"].parent_id == recs["loader/next_batch"].id
+    assert recs["loader/poll"].trace_id == 7          # inherited
+    assert recs["compile"].parent_id == step.id
+    assert (recs["compile"].t0, recs["compile"].t1) == (0.1, 0.2)
+    assert recs["other"].parent_id is None and recs["other"].trace_id is None
+    # children end before their parents; a parent holds its children
+    assert [r.name for r in tr.spans()][:2] == ["loader/poll",
+                                                "loader/next_batch"]
+    assert step.t0 < recs["loader/poll"].t0 < recs["loader/poll"].t1 < step.t1
+    assert [r.name for r in tr.spans("loader/poll")] == ["loader/poll"]
+
+
+def test_tracer_parents_are_per_thread():
+    from repro.core.telemetry import Tracer
+    tr = Tracer()
+    with tr.span("main"):
+        t = threading.Thread(target=lambda: tr.span("worker").__enter__()
+                             .__exit__(None, None, None))
+        t.start()
+        t.join(timeout=10)
+    assert not t.is_alive()
+    assert {r.name: r.parent_id for r in tr.spans()}["worker"] is None
+
+
+def test_tracer_ring_bound_and_dropped():
+    from repro.core.telemetry import Tracer
+    clock = _FakeClock(step=1.0)
+    tr = Tracer(capacity=3, clock=clock)
+    for i in range(5):
+        with tr.span("s", trace_id=i):
+            pass
+    kept = tr.spans()
+    assert [r.trace_id for r in kept] == [2, 3, 4]
+    assert tr.dropped == 2
+    # the latest end among the dropped: span 1 ran from t=3 to t=4
+    assert tr.dropped_until == 4.0
+    # the histogram kept every span, dropped or not
+    assert tr.registry.histogram("span_seconds", span="s").count == 5
+    with pytest.raises(ValueError):
+        Tracer(capacity=0)
+
+
+def test_tracer_histogram_sums_are_exact():
+    from repro.core.telemetry import Tracer
+    tr = Tracer(clock=_FakeClock(step=0.25))
+    for _ in range(4):
+        with tr.span("a"):
+            pass
+    tr.record("b", 1.0, 3.5)
+    h = tr.registry.histogram("span_seconds", span="a")
+    assert h.count == 4 and h.sum_seconds == 1.0        # 4 x 0.25 s
+    assert tr.registry.histogram("span_seconds", span="b").sum_seconds == 2.5
+    assert sum(r.t1 - r.t0 for r in tr.spans("a")) == h.sum_seconds
+
+
+def test_tracer_counters_render_beside_histograms():
+    from repro.core.telemetry import Tracer
+    reg = MetricsRegistry()
+    reg.histogram("poll_seconds", connector="rss").record(0.002)
+    tr = Tracer(registry=reg)
+    tr.count("loader_records", 64)
+    tr.count("loader_records", 3)
+    tr.count("loader_batches")
+    assert tr.value("loader_records") == 67 and tr.value("never") == 0
+    with tr.span("loader/poll"):
+        pass
+    snap = reg.collect()
+    assert snap["counters"] == {"loader_records": 67, "loader_batches": 1}
+    assert 'span_seconds{span="loader/poll"}' in snap["histograms"]
+    text = reg.render_text()
+    assert "repro_loader_records_total 67" in text
+    assert "repro_loader_batches_total 1" in text
+    assert 'repro_span_seconds_count{span="loader/poll"} 1' in text
+    assert 'repro_poll_seconds_count{connector="rss"} 1' in text
+    json.loads(reg.to_json())
+
+
+def test_tracer_labelled_counters_are_separate():
+    from repro.core.telemetry import Tracer
+    tr = Tracer()
+    tr.count("loader_starved_polls", 2, loader="0")
+    tr.count("loader_starved_polls", loader="1")
+    assert tr.value("loader_starved_polls", loader="0") == 2
+    assert tr.value("loader_starved_polls", loader="1") == 1
+    assert tr.value("loader_starved_polls") == 0
+    text = tr.registry.render_text()
+    assert 'repro_loader_starved_polls_total{loader="0"} 2' in text
+    assert 'repro_loader_starved_polls_total{loader="1"} 1' in text
+
+
+def test_tracer_annotation_factory_wraps_each_span():
+    from repro.core.telemetry import Tracer
+    events = []
+
+    class FakeAnnotation:
+        def __init__(self, name):
+            self.name = name
+            events.append(("make", name))
+
+        def __enter__(self):
+            events.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            events.append(("exit", self.name))
+
+    tr = Tracer()
+    tr.set_annotation(FakeAnnotation)
+    with tr.span("train/step"):
+        with tr.span("train/dispatch"):
+            pass
+    tr.record("compile", 0.0, 1.0)          # back-dated: no annotation
+    assert events == [("make", "train/step"), ("enter", "train/step"),
+                      ("make", "train/dispatch"), ("enter", "train/dispatch"),
+                      ("exit", "train/dispatch"), ("exit", "train/step")]
+    # a factory may decline (no profiler session): the span still records
+    tr.set_annotation(lambda name: None)
+    with tr.span("quiet"):
+        pass
+    tr.set_annotation(None)
+    assert [r.name for r in tr.spans()][-1] == "quiet"
+
+
+def test_tracer_exception_closes_span():
+    from repro.core.telemetry import Tracer
+    tr = Tracer()
+    with pytest.raises(RuntimeError):
+        with tr.span("outer"):
+            raise RuntimeError("boom")
+    with tr.span("after"):
+        pass
+    assert {r.name: r.parent_id for r in tr.spans()}["after"] is None
+
+
+def test_process_tracer_functions():
+    from repro.core import telemetry
+    before = telemetry.tracer().value("test_process_counter")
+    telemetry.count("test_process_counter", 2)
+    with telemetry.span("test/process_span", trace_id=3):
+        pass
+    tr = telemetry.tracer()
+    assert tr.value("test_process_counter") == before + 2
+    assert tr.spans("test/process_span")[-1].trace_id == 3

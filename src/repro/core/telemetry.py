@@ -489,6 +489,11 @@ class Tracer:
     for step or batch granularity: a span costs a clock pair, a ring append
     and a histogram record, never work per record.
 
+    The program's counters: ``loader_*`` (``data/loader.py``), ``compiles``
+    (``runtime/tracing.py``) and ``ssd_path{path=kernel|chunked}``, one
+    count per trace of the SSD op by the path it compiled
+    (``kernels/ssd/ops.py``).
+
     ``set_annotation(factory)``: each span also enters ``factory(name)``
     unless it returns None: a context manager such as
     ``jax.profiler.TraceAnnotation``, so that a profiler session records

@@ -3,8 +3,9 @@
 ``ssd_sequential``  — literal per-timestep recurrence (ground truth).
 ``ssd_chunked``     — the chunked SSD algorithm (Mamba-2 paper §6): quadratic
                       attention-like compute inside chunks, linear state
-                      passing between chunks. This is what the model lowers
-                      on the dry-run and what the Pallas kernel implements.
+                      passing between chunks. This is what the model runs
+                      on the CPU and under a mesh, and what the Pallas
+                      kernels (kernel.py) implement on one TPU device.
 
 Shapes (already projected/conv'd by the caller):
   x  (B, S, H, P)   head channels

@@ -46,16 +46,12 @@ def _project_streams(h, p, cfg, ctx: ShardCtx):
 
 
 def _to_heads(xs, bs, cs, cfg):
+    """x split into heads (B,S,H,P); B and C into groups (B,S,G,N), at
+    group width: the SSD op reads a group once for all of its heads."""
     b, s, _ = xs.shape
-    nh, hp = cfg.ssm_heads, cfg.ssm_headdim
     g, n = cfg.ssm_ngroups, cfg.ssm_state
-    x = xs.reshape(b, s, nh, hp)
-    bm = bs.reshape(b, s, g, n)
-    cm = cs.reshape(b, s, g, n)
-    rep = nh // g
-    bm = jnp.repeat(bm, rep, axis=2)                  # (B,S,H,N)
-    cm = jnp.repeat(cm, rep, axis=2)
-    return x, bm, cm
+    x = xs.reshape(b, s, cfg.ssm_heads, cfg.ssm_headdim)
+    return x, bs.reshape(b, s, g, n), cs.reshape(b, s, g, n)
 
 
 def _conv_heads(xs, bs, cs, p, cfg, dtype, cache=None):
@@ -72,13 +68,14 @@ def _conv_heads(xs, bs, cs, p, cfg, dtype, cache=None):
         return (*_to_heads(*outs, cfg), carry)
 
 
-def _ssd_skip(x, dt, bm, cm, p, cfg, state=None):
+def _ssd_skip(x, dt, bm, cm, p, cfg, ctx: ShardCtx, state=None):
     """The SSD over the sequence (or, from ``state``, one decode step) plus
     the D skip. Returns (y, final state)."""
     with jax.named_scope("ssd"):
         A = -jnp.exp(p["A_log"].astype(jnp.float32))
         if state is None:
-            y, state = ssd_ops.ssd(x, dt, A, bm, cm, chunk=cfg.ssm_chunk)
+            y, state = ssd_ops.ssd(x, dt, A, bm, cm, chunk=cfg.ssm_chunk,
+                                   mesh=ctx.mesh)
         else:
             y, state = ssd_ops.ssd_decode_step(
                 state, x[:, 0], dt[:, 0], A, bm[:, 0], cm[:, 0])
@@ -105,7 +102,7 @@ def mamba_forward(h, p, cfg, ctx: ShardCtx):
     """Training/prefill path over a full sequence. h: (B,S,d)."""
     z, xs, bs, cs, dt = _streams(h, p, cfg, ctx)
     x, bm, cm, _ = _conv_heads(xs, bs, cs, p, cfg, h.dtype)
-    y, _ = _ssd_skip(x, dt, bm, cm, p, cfg)
+    y, _ = _ssd_skip(x, dt, bm, cm, p, cfg, ctx)
     return _gate_out(y, z, p, cfg, h)
 
 
@@ -113,7 +110,7 @@ def mamba_prefill(h, p, cfg, ctx: ShardCtx):
     """Like forward but also returns the recurrent cache for decode."""
     z, xs, bs, cs, dt = _streams(h, p, cfg, ctx)
     x, bm, cm, carry = _conv_heads(xs, bs, cs, p, cfg, h.dtype)
-    y, state = _ssd_skip(x, dt, bm, cm, p, cfg)
+    y, state = _ssd_skip(x, dt, bm, cm, p, cfg, ctx)
     cache = {"ssm": state, **carry}                # ssm: (B,H,N,P) fp32
     return _gate_out(y, z, p, cfg, h), cache
 
@@ -122,7 +119,7 @@ def mamba_decode(h, p, cfg, ctx: ShardCtx, cache):
     """One-token step. h: (B,1,d). cache: {'ssm','conv_x','conv_B','conv_C'}."""
     z, xs, bs, cs, dt = _streams(h, p, cfg, ctx)
     x, bm, cm, carry = _conv_heads(xs, bs, cs, p, cfg, h.dtype, cache)
-    y, state = _ssd_skip(x, dt, bm, cm, p, cfg, cache["ssm"])
+    y, state = _ssd_skip(x, dt, bm, cm, p, cfg, ctx, cache["ssm"])
     return _gate_out(y, z, p, cfg, h), {"ssm": state, **carry}
 
 

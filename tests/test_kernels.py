@@ -180,6 +180,64 @@ def test_ssd_chunked_gradient_finite_under_strong_decay():
     assert all(bool(jnp.isfinite(g).all()) for g in grads)
 
 
+@pytest.mark.parametrize("b,s,h,g,p,n,chunk,strong", [
+    (2, 64, 4, 1, 16, 8, 16, False),    # one group of 4 heads
+    (1, 64, 4, 2, 16, 8, 16, False),    # two groups of 2 heads
+    (1, 100, 4, 2, 8, 8, 32, False),    # non-multiple sequence (internal pad)
+    (1, 64, 16, 1, 8, 8, 16, False),    # 16 heads of 8 lanes share a tile
+    (1, 64, 6, 1, 64, 16, 16, False),   # heads of 64 lanes, two a tile
+    (1, 64, 32, 1, 8, 8, 16, False),    # two head blocks of 16 (mamba2's split)
+    (1, 64, 20, 1, 64, 16, 16, False),  # two head blocks of 10, two a tile
+    (2, 64, 64, 2, 8, 8, 16, False),    # two groups, each two head blocks
+    (1, 32, 50, 1, 64, 16, 16, False),  # five head blocks of 10 (hymba's)
+    (1, 512, 2, 1, 8, 8, 256, True),    # A = -16, dt = 0.1 (strong decay)
+])
+def test_ssd_kernel_gradient_matches_sequential(b, s, h, g, p, n, chunk,
+                                                strong):
+    """The custom VJP against jax.grad of the per-step recurrence, in fp32,
+    through a loss that weighs y and the final state (so the final state's
+    cotangent is not zero). Both run in fp32 on the CPU, the interpreter
+    exactly as the recurrence; the gaps are fp32 rounding over differently
+    ordered sums (chunks of up to 256 steps against a 512-step scan), so
+    2e-4 of each gradient's largest entry, in the strong-decay case too,
+    where e^(±409) above the diagonal must not turn into inf or NaN."""
+    ks = jax.random.split(jax.random.PRNGKey(11), 7)
+    x = jax.random.normal(ks[0], (b, s, h, p))
+    B = jax.random.normal(ks[1], (b, s, g, n))
+    C = jax.random.normal(ks[2], (b, s, g, n))
+    if strong:
+        dt, A = jnp.full((b, s, h), 0.1), jnp.full((h,), -16.0)
+    else:
+        dt = jax.nn.softplus(jax.random.normal(ks[3], (b, s, h)))
+        A = -jnp.exp(jax.random.normal(ks[4], (h,)))
+    wy = jax.random.normal(ks[5], (b, s, h, p))
+    ws = jax.random.normal(ks[6], (b, h, n, p))
+
+    def per_head(t):
+        return jnp.repeat(t, h // g, axis=2)
+
+    def kernel(x, dt, A, B, C):
+        return ssd_pallas(x, dt, A, B, C, chunk=chunk, interpret=True)
+
+    def oracle(x, dt, A, B, C):
+        return ssd_ref.ssd_sequential(x, dt, A, per_head(B), per_head(C))
+
+    def loss(f):
+        def of(*args):
+            y, state = f(*args)
+            return jnp.sum(y * wy) + jnp.sum(state * ws)
+        return jax.grad(of, argnums=(0, 1, 2, 3, 4))
+
+    with jax.default_matmul_precision("highest"):
+        got = loss(kernel)(x, dt, A, B, C)
+        want = loss(oracle)(x, dt, A, B, C)
+    for name, a, r in zip(("dx", "d(dt)", "dA", "dB", "dC"), got, want):
+        assert a.shape == r.shape and bool(jnp.isfinite(a).all()), name
+        scale = float(jnp.abs(r).max())
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r), rtol=0,
+                                   atol=2e-4 * scale, err_msg=name)
+
+
 @given(chunk=st.sampled_from([16, 32, 64]), s_mult=st.integers(2, 6))
 @settings(deadline=None, max_examples=8)
 def test_ssd_chunk_size_invariance(chunk, s_mult):
